@@ -9,8 +9,11 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.utils.compile_cache import enable_compile_cache
+
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--full", action="store_true",
                     help="paper-scale sizes (hours); default is quick mode")

@@ -25,6 +25,7 @@ from repro.configs.base import FedConfig
 from repro.configs.paper_models import FMNIST_CNN, reduced
 from repro.data.synthetic import make_classification
 from repro.edge import ChannelConfig, DeviceConfig, EdgeConfig
+from repro.utils.compile_cache import enable_compile_cache
 
 CHANNEL = ChannelConfig(bandwidth_hz=2e5, snr_db_mean=10.0, snr_db_std=3.0,
                         fading="rayleigh", server_rate_bps=1.5e6,
@@ -143,6 +144,7 @@ BLURBS = {
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--only", default=None, metavar="CASE",
                     help="run one named demo case (default: all)")

@@ -8,9 +8,11 @@ from repro.configs.base import FedConfig
 from repro.configs.paper_models import FMNIST_CNN, reduced
 from repro.data.synthetic import make_classification
 from repro.fed.server import FederatedRun
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     mcfg = reduced(FMNIST_CNN)
     train, test = make_classification(mcfg, n_train=1500, n_test=400,
                                       seed=0, noise=0.8)

@@ -16,9 +16,11 @@ from repro import checkpoint
 from repro.configs.granite_8b import CONFIG
 from repro.data.synthetic import zipf_tokens
 from repro.launch import train as trainlib
+from repro.utils.compile_cache import enable_compile_cache
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--seq", type=int, default=64)

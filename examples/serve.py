@@ -12,6 +12,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models import model as zoo
+from repro.utils.compile_cache import enable_compile_cache
 
 ARCH_MODULES = {
     "granite-8b": "granite_8b", "mamba2-370m": "mamba2_370m",
@@ -21,6 +22,7 @@ ARCH_MODULES = {
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="mamba2-370m", choices=sorted(ARCH_MODULES))
     ap.add_argument("--batch", type=int, default=4)
@@ -43,7 +45,8 @@ def main():
     dt = time.time() - t0
     gen = jnp.stack(out, axis=1)
     print(f"{args.arch} ({cfg.name}): generated {gen.shape} tokens in {dt:.2f}s "
-          f"({args.batch*args.tokens/dt:.1f} tok/s on CPU)")
+          f"({args.batch*args.tokens/dt:.1f} tok/s on "
+          f"{jax.devices()[0].platform})")
     print("sample:", gen[0][:16].tolist())
 
 

@@ -7,7 +7,7 @@ fail in seconds on a bare Python install:
   ======== ================== ==============================================
   RPL001   sim-determinism    no wall clocks / global RNG in edge, fed, obs
   RPL002   x64-hygiene        no module-level jax.config.update; fleet
-                              kernels called under ``with enable_x64():``
+                              kernels under ``with jax.enable_x64(True):``
   RPL003   jit-purity         no host syncs / Python branching on tracers
                               inside jitted kernels
   RPL004   registry-contract  registered strategies/codecs/policies declare

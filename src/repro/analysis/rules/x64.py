@@ -1,7 +1,7 @@
 """RPL002 — x64-hygiene: keep float64 a *scoped* choice.
 
 PR 7 established the convention: the fleet's jitted kernels run under
-``with jax.experimental.enable_x64():`` at their call sites, so x64 is
+``with jax.enable_x64(True):`` at their call sites, so x64 is
 an explicitly scoped property of the fleet fast path — never a
 process-global flip that silently changes every other kernel's dtypes
 (the Pallas kernels and the fed training loop are f32).
@@ -13,7 +13,7 @@ Two checks:
     the whole process);
   * in ``edge/fleet/`` files, any call to a function the same module
     decorated with ``jax.jit`` must sit lexically inside a
-    ``with enable_x64():`` block.
+    ``with jax.enable_x64(True):`` block.
 """
 from __future__ import annotations
 
@@ -23,7 +23,7 @@ from repro.analysis.core import ModuleSource, Rule, register
 
 JIT_NAMES = {"jax.jit", "jit"}
 PARTIAL_NAMES = {"partial", "functools.partial"}
-ENABLE_X64 = {"enable_x64", "jax.experimental.enable_x64"}
+ENABLE_X64 = {"jax.enable_x64"}
 
 
 def jit_decorated_functions(mod: ModuleSource) -> dict:
@@ -80,7 +80,7 @@ class X64HygieneRule(Rule):
     title = "x64-hygiene"
     description = ("no module-level jax.config.update; calls to "
                    "jit-decorated fleet kernels must sit under "
-                   "`with enable_x64():` (the PR-7 scoping)")
+                   "`with jax.enable_x64(True):` (the PR-7 scoping)")
 
     def check(self, mod: ModuleSource) -> list:
         out = []
@@ -93,7 +93,7 @@ class X64HygieneRule(Rule):
                     mod, node,
                     "module-level jax.config.update flips numerics for "
                     "the whole process on import — scope x64 with `with "
-                    "enable_x64():` at the call site instead"))
+                    "jax.enable_x64(True):` at the call site instead"))
         if "edge/fleet/" in mod.path:
             out.extend(self._check_fleet_scoping(mod))
         return out
@@ -115,6 +115,6 @@ class X64HygieneRule(Rule):
             out.append(self.finding(
                 mod, node,
                 f"call to jitted kernel {name}() outside `with "
-                "enable_x64():` — fleet kernels must match the float64 "
+                "jax.enable_x64(True):` — fleet kernels must match the float64 "
                 "numpy references (PR-7 scoping)"))
         return out

@@ -162,9 +162,9 @@ def combine(h: History, g, delta):
     return jax.tree.map(leaf, h.s, h.y, g)
 
 
-def _gram_via_kernel(h: History, g, kernels: str):
-    """Gram matrix through the blocked Pallas kernel: materialize the
-    (2m+1, D) basis [s_0.., y_0.., g] by raveling every history leaf.
+def gram_basis(h: History, g):
+    """The (2m+1, D) basis [s_0.., y_0.., g] the Gram kernel reads,
+    materialized by raveling every history leaf.
 
     This is the single-host/paper-scale fast path — the reshape+concat
     that ``gram_matrix`` deliberately avoids is exactly what lets one
@@ -178,8 +178,7 @@ def _gram_via_kernel(h: History, g, kernels: str):
 
     gflat = jnp.concatenate(
         [leaf.ravel().astype(jnp.float32) for leaf in jax.tree.leaves(g)])
-    basis = jnp.concatenate([rows(h.s), rows(h.y), gflat[None]], axis=0)
-    return kernel_ops.vlbfgs_gram(basis, mode=kernels)
+    return jnp.concatenate([rows(h.s), rows(h.y), gflat[None]], axis=0)
 
 
 def direction(h: History, g, kernels: str = "off"):
@@ -193,7 +192,7 @@ def direction(h: History, g, kernels: str = "off"):
     if kernel_ops.resolve(kernels) == "oracle":
         M = gram_matrix(h, g)
     else:
-        M = _gram_via_kernel(h, g, kernels)
+        M = kernel_ops.vlbfgs_gram(gram_basis(h, g), mode=kernels)
     delta = direction_coeffs(M, h.idx, h.count, m)
     return combine(h, g, delta)
 

@@ -5,7 +5,11 @@ Computes  new = ema*old + (1-ema) * mean_b(g[b, :]**2)  in one pass over the
 gradient tile is read from HBM exactly once (the op is purely memory-bound:
 2 flops/byte).  Tiled (B_BLK, D_BLK) over VMEM with the batch dimension as
 the *minor* grid axis so the f32 accumulator tile stays resident while the
-batch is reduced (TPU grids iterate minor-to-major sequentially).
+batch is reduced (TPU grids iterate minor-to-major sequentially).  The
+diagonal rides as a (1, D) row so its blocks keep two dims under vmap
+(the vmapped cohort step batches this kernel over clients): the TPU
+compiler needs a block's last two dims to tile as (8, 128) or span the
+array.
 """
 from __future__ import annotations
 
@@ -27,7 +31,7 @@ def _kernel(g_ref, old_ref, ema_ref, out_ref, *, nb: int, batch: int):
         out_ref[...] = jnp.zeros_like(out_ref)
 
     g = g_ref[...].astype(jnp.float32)
-    out_ref[...] += jnp.sum(g * g, axis=0)
+    out_ref[...] += jnp.sum(g * g, axis=0, keepdims=True)
 
     @pl.when(b == nb - 1)
     def _finish():
@@ -49,20 +53,20 @@ def fim_diag(grads, old_diag, ema, interpret: bool = False):
     # padded diag tail is sliced off below
     if B % bb or D % db:
         grads = jnp.pad(grads, ((0, nb * bb - B), (0, nd * db - D)))
-    old_diag = old_diag.astype(jnp.float32)
+    old_diag = old_diag.astype(jnp.float32).reshape(1, D)
     if D % db:
-        old_diag = jnp.pad(old_diag, (0, nd * db - D))
+        old_diag = jnp.pad(old_diag, ((0, 0), (0, nd * db - D)))
     ema = jnp.asarray(ema, jnp.float32).reshape(1)
     out = pl.pallas_call(
         functools.partial(_kernel, nb=nb, batch=B),
         grid=(nd, nb),
         in_specs=[
             pl.BlockSpec((bb, db), lambda d, b: (b, d)),
-            pl.BlockSpec((db,), lambda d, b: (d,)),
+            pl.BlockSpec((1, db), lambda d, b: (0, d)),
             pl.BlockSpec((1,), lambda d, b: (0,)),
         ],
-        out_specs=pl.BlockSpec((db,), lambda d, b: (d,)),
-        out_shape=jax.ShapeDtypeStruct((nd * db,), jnp.float32),
+        out_specs=pl.BlockSpec((1, db), lambda d, b: (0, d)),
+        out_shape=jax.ShapeDtypeStruct((1, nd * db), jnp.float32),
         interpret=interpret,
     )(grads, old_diag, ema)
-    return out[:D]
+    return out[0, :D]

@@ -97,9 +97,6 @@ def _moe_apply_expert_parallel(p, cfg, x, mesh, batch_axes):
     E = cfg.num_experts
     model_size = mesh.shape["model"]
     E_local = E // model_size
-    shard_fn = getattr(jax, "shard_map", None)
-    if shard_fn is None:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map as shard_fn
 
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0] if batch_axes else None)
 
@@ -163,7 +160,7 @@ def _moe_apply_expert_parallel(p, cfg, x, mesh, batch_axes):
             dropped = jax.lax.pmean(dropped, batch_axes)
         return out.reshape(Bl, S, d), aux, dropped
 
-    out, aux, dropped = shard_fn(
+    out, aux, dropped = jax.shard_map(
         local_moe, mesh=mesh,
         in_specs=(P(bspec, None, None), P(None, None),
                   P("model", None, None), P("model", None, None),

@@ -136,14 +136,13 @@ RPL002_BAD = """\
 RPL002_GOOD = """\
     import jax
     from functools import partial
-    from jax.experimental import enable_x64
 
     @partial(jax.jit, static_argnames=("iters",))
     def _widths(x, iters):
         return x * iters
 
     def widths(x, iters=5):
-        with enable_x64():
+        with jax.enable_x64(True):
             return _widths(x, iters)
 """
 

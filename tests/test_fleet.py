@@ -31,7 +31,6 @@ from repro.data.synthetic import make_classification
 from repro.edge import (ChannelConfig, DeviceConfig, EdgeConfig,
                         EdgeRuntime, FleetEngine)
 from repro.edge.fleet import FleetState
-from repro.edge.fleet.kernel import HAVE_JAX
 from repro.obs.export import to_chrome
 from repro.obs.metrics import PlanAudit
 from repro.obs.trace import CAT_CLIENT, CAT_ROUND, Tracer
@@ -41,8 +40,6 @@ UPLINK = ChannelConfig(bandwidth_hz=2e5, snr_db_mean=10.0, snr_db_std=3.0,
 HETERO = DeviceConfig(flops_per_s_mean=2e9, flops_per_s_sigma=1.0)
 UP, DOWN, FLOPS = 80_000.0, 40_000.0, 1e9
 POLICIES = ["uniform", "bandwidth_opt", "energy_opt"]
-
-needs_jax = pytest.mark.skipif(not HAVE_JAX, reason="jax unavailable")
 
 
 def _cfg(policy="uniform", **kw):
@@ -68,8 +65,7 @@ def test_fleet_state_draw_and_alive_mask():
     assert not mask[3] and not mask[5] and mask.sum() == 62
 
 
-@pytest.mark.parametrize("backend", ["exact", pytest.param(
-    "jit", marks=needs_jax)])
+@pytest.mark.parametrize("backend", ["exact", "jit"])
 def test_cohort_without_replacement_from_alive_only(backend):
     eng = _engine("uniform", pop=100, backend=backend)
     eng.state.fleet.battery_j[:20] = 0.0    # shared with the runtime view
@@ -81,7 +77,6 @@ def test_cohort_without_replacement_from_alive_only(backend):
         assert ids.min() >= 20                          # depleted excluded
 
 
-@needs_jax
 def test_busy_mask_respected_on_jit_backend():
     eng = _engine("uniform", pop=40, backend="jit")
     eng.state.busy[:30] = True
@@ -112,7 +107,6 @@ def test_engine_exact_is_bit_identical_to_dict_runtime(policy):
     assert np.array_equal(eng.state.battery_j, rt.fleet.battery_j)
 
 
-@needs_jax
 @pytest.mark.parametrize("policy", POLICIES)
 def test_jit_backend_matches_exact(policy):
     """Same seed, same rounds: the jit backend must draw the SAME
@@ -134,8 +128,7 @@ def test_jit_backend_matches_exact(policy):
 
 
 # -------------------------------------------------------- round contracts
-@pytest.mark.parametrize("backend", ["exact", pytest.param(
-    "jit", marks=needs_jax)])
+@pytest.mark.parametrize("backend", ["exact", "jit"])
 def test_empty_cohort_round_records_zero_and_clock_unchanged(backend):
     """All batteries depleted: the round records cohort=0 / dropped=0
     and the clock does not advance (nobody transmitted) — the PR-3
@@ -148,8 +141,7 @@ def test_empty_cohort_round_records_zero_and_clock_unchanged(backend):
     assert eng.last_decision is None or eng.last_decision.n_selected == 0
 
 
-@pytest.mark.parametrize("backend", ["exact", pytest.param(
-    "jit", marks=needs_jax)])
+@pytest.mark.parametrize("backend", ["exact", "jit"])
 def test_all_dropped_round_bills_partials_and_advances_clock(backend):
     """An infeasibly tight hard deadline drops the whole cohort: the
     record shows cohort=0 with every selected client dropped, the

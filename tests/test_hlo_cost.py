@@ -62,7 +62,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P, NamedSharding
 from repro.launch.hlo_cost import analyze
-mesh = jax.make_mesh((4,), ("model",))
+mesh = jax.make_mesh((4,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 def f(x, w):
     def step(c, _):
         return jnp.einsum("bd,df->bf", c, w), None   # TP AR per iteration

@@ -73,7 +73,7 @@ def test_flash_attention_bf16():
 
 # ------------------------------------------------------- codec kernels
 @pytest.mark.parametrize("shape", [(7,), (1000,), (33, 129), (4096,),
-                                   (300, 17)])
+                                   (300, 17), (3, 700, 129)])
 def test_int8_roundtrip_kernel_bit_identical_to_oracle(shape):
     """The fused int8 kernel and the jnp oracle consume the same uniform
     draws, so they must agree bit-for-bit (not allclose)."""
@@ -89,7 +89,8 @@ def test_int8_roundtrip_kernel_bit_identical_to_oracle(shape):
 
 
 @pytest.mark.parametrize("n,k", [(8, 2), (35, 4), (1000, 100), (5000, 1),
-                                 (2048, 2048), (1537, 700), (1024, 1)])
+                                 (2048, 2048), (1537, 700), (1024, 1),
+                                 (100_000, 999)])
 def test_topk_select_kernel_bit_identical_to_oracle(n, k):
     """Histogram + threshold-select kernel vs the jnp oracle: identical
     integer bucket logic, so keep masks match bit-for-bit and exactly k
@@ -119,6 +120,18 @@ def test_topk_select_handles_threshold_ties():
         out_r = ref.topk_select_ref(flat, k)
         assert bool(jnp.all(out_k == out_r)), k
         assert int(jnp.sum(out_k != 0)) == k
+
+
+@pytest.mark.parametrize("k", [1, 50_001, 80_000, 120_000])
+def test_topk_select_ties_span_blocks(k):
+    """A tie bucket spread over several (rows, 128) blocks: the rank
+    carried across the sequential grid keeps the first ``need`` ties in
+    index order, as the oracle does."""
+    from repro.kernels import codec_ops
+    flat = jnp.tile(jnp.asarray([1.0, -1.0, 0.5]), 40_000)
+    out_k = codec_ops.topk_select(flat, k, interpret=True)
+    assert bool(jnp.all(out_k == ref.topk_select_ref(flat, k)))
+    assert int(jnp.sum(out_k != 0)) == k
 
 
 def test_topk_select_matches_sort_semantics():

@@ -58,7 +58,8 @@ from repro.models import model as zoo
 from repro.utils import sharding as shd
 from repro.models.layers import use_mesh
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = smoke_config().replace(dtype="float32")
 shape = ShapeConfig("t", 64, 8, "train")
 step, arg_shapes, arg_axes, donate = build_step(cfg, shape, "fim_lbfgs", 2)
