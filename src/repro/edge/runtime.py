@@ -122,9 +122,8 @@ class EdgeRuntime:
                  tracer=None):
         self.cfg = cfg
         self.num_clients = num_clients
-        # obs: spans/events/metrics go here; the shared no-op default
-        # keeps the untraced hot path free (one attribute check per site)
-        self.tracer = tracer if tracer is not None else obs.NULL_TRACER
+        self.async_agg: Optional[AsyncAggregator] = None
+        self.tracer = tracer
         s = seed + cfg.seed
         self.channel = Channel(cfg.channel, num_clients, seed=s + 1)
         self.fleet = DeviceFleet(cfg.device, num_clients, seed=s + 2)
@@ -141,13 +140,12 @@ class EdgeRuntime:
             min_clients=cfg.min_clients, battery_floor_j=cfg.battery_floor_j,
             round_budget_j=cfg.round_budget_j, ratio=cfg.adaptive_ratio,
             ratio_floor=cfg.adaptive_ratio_floor)
-        self.async_agg: Optional[AsyncAggregator] = None
         if cfg.mode == "async":
             # buffer_size 0 = auto: half the dispatched cohort, resolved at
             # the first dispatch (see dispatch_async)
             self.async_agg = AsyncAggregator(
                 self.clock, buffer_size=max(cfg.buffer_size, 1),
-                alpha=cfg.staleness_alpha, tracer=self.tracer)
+                alpha=cfg.staleness_alpha, tracer=self._tracer)
         self.busy: set[int] = set()      # async: clients with work in flight
         self._held_hz: dict[int, float] = {}  # async: spectrum still on the
                                               # air from earlier dispatches
@@ -179,6 +177,20 @@ class EdgeRuntime:
         self._fleet_round = False   # last commit used the fleet fast path
                                     # (caps per-client tracing to
                                     # cfg.trace_top_k_clients)
+
+    # obs: spans/events/metrics go here; the shared no-op default keeps
+    # the untraced hot path free (one attribute check per site)
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        """The runtime's tracer (None: ``NULL_TRACER``), handed on to the
+        async aggregator; settable after construction."""
+        self._tracer = obs.NULL_TRACER if tracer is None else tracer
+        if self.async_agg is not None:
+            self.async_agg.tracer = self._tracer
 
     # ------------------------------------------------------------------
     def fleet_active(self) -> bool:

@@ -122,8 +122,9 @@ def make_fedprox_fn(loss_fn: Callable):
 
 
 def stack_batches(xs, ys, batch_size: int, epochs: int, rng):
-    """Materialize E epochs of shuffled minibatches as stacked arrays for
-    lax.scan (static shapes: drops ragged tails)."""
+    """Materialize E epochs of shuffled minibatches as stacked host
+    arrays for lax.scan (static shapes: drops ragged tails); the strategy
+    copies them to the device (``FedStrategy._put``)."""
     n = len(xs)
     bs = min(batch_size, n)
     nb = max(1, n // bs)
@@ -134,4 +135,4 @@ def stack_batches(xs, ys, batch_size: int, epochs: int, rng):
             idx = order[i * bs:(i + 1) * bs]
             bx.append(xs[idx])
             by.append(ys[idx])
-    return {"x": jnp.asarray(np.stack(bx)), "y": jnp.asarray(np.stack(by))}
+    return {"x": np.stack(bx), "y": np.stack(by)}

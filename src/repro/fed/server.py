@@ -21,6 +21,11 @@ owns everything algorithm-independent:
     deltas keep their correction,
   * synchronous edge finishing and buffered-async aggregation — async is
     available to any strategy whose plan marks its payload ``summable``,
+  * host spans on the run's tracer, one per step of the round
+    (``fed.round`` > ``fed.sample``, ``fed.gather``, ``fed.client`` >
+    [``fed.put``, ``fed.dispatch``, ``fed.wait``, ``fed.compress``],
+    ``fed.aggregate``, ``fed.server_step``; ``fed.evaluate``): the
+    profiler records them beside the device ops (repro.obs.trace),
   * deadline enforcement: ``Allocation.deadline_s`` is a runtime
     contract — a client whose realized finish busts its grant is cut off
     at the barrier (upload discarded whole, on-air bytes billed, the
@@ -38,7 +43,6 @@ partitions.
 """
 from __future__ import annotations
 
-import time
 from typing import Optional
 
 import jax
@@ -54,10 +58,12 @@ from repro.fed import codecs, comm, strategies
 from repro.obs import trace as obs
 
 
-def _tree_norm(tree) -> float:
-    """L2 norm over every leaf of a pytree (error-feedback residuals)."""
-    return float(np.sqrt(sum(float(jnp.vdot(leaf, leaf).real)
-                             for leaf in jax.tree.leaves(tree))))
+@jax.jit
+def _tree_norm(tree):
+    """L2 norm over every leaf of a pytree (error-feedback residuals), as
+    one device reduction."""
+    return jnp.sqrt(sum(jnp.vdot(leaf, leaf).real
+                        for leaf in jax.tree.leaves(tree)))
 
 
 class FederatedRun:
@@ -71,9 +77,6 @@ class FederatedRun:
         self.fcfg = fed_cfg
         self.train, self.test = train, test
         self.algorithm = algorithm
-        # obs: spans/events/metrics/audit; the shared no-op default keeps
-        # the untraced driver free (one attribute check per site)
-        self.tracer = tracer if tracer is not None else obs.NULL_TRACER
         self.rng = np.random.default_rng(fed_cfg.seed)
         self.ledger = comm.CommLedger()
         self._qkey = jax.random.PRNGKey(fed_cfg.seed + 17)
@@ -97,7 +100,7 @@ class FederatedRun:
                     "async edge mode needs summable client payloads; "
                     f"{algorithm!r} supports sync edge simulation only")
             self.edge = EdgeRuntime(fed_cfg.edge, fed_cfg.num_clients,
-                                    fed_cfg.seed, tracer=self.tracer)
+                                    fed_cfg.seed)
             if self.edge.policy.needs_summable and not self.plan.summable:
                 raise ValueError(
                     f"allocation policy {fed_cfg.edge.scheduler!r} emits "
@@ -114,6 +117,27 @@ class FederatedRun:
         # in python instead of O(population) list comprehensions
         self._eligible: Optional[list[int]] = None
         self._eligible_flops: Optional[np.ndarray] = None
+        self._tracer = obs.NULL_TRACER
+        self.tracer = tracer
+
+    # ------------------------------------------------------------------
+    # obs: spans/events/metrics/audit; the shared no-op default keeps the
+    # untraced driver free (one attribute check per site)
+    @property
+    def tracer(self):
+        return self._tracer
+
+    @tracer.setter
+    def tracer(self, tracer) -> None:
+        """Attach ``tracer`` (None: the no-op ``NULL_TRACER``) to the run,
+        its strategy and its edge runtime, and detach the one before; a
+        tracer may be attached for some rounds only."""
+        tracer = obs.NULL_TRACER if tracer is None else tracer
+        self._tracer.detach()
+        tracer.attach()
+        self._tracer = self.strategy.tracer = tracer
+        if self.edge is not None:
+            self.edge.tracer = tracer
 
     # ------------------------------------------------------------------
     # checkpoint/resume (repro.checkpoint.run_state): sync-mode runs
@@ -344,87 +368,103 @@ class FederatedRun:
         (a hard drop: no partial deltas, no error-feedback update), the
         server aggregates the on-time partial cohort with re-normalized
         n_k weights, and the ledger bills only their on-air bytes."""
-        selected = self.sample_clients()
-        n_dropped = (0 if self._decision is None
-                     else self._decision.n_dropped)
-        # survivors preserves selection order on both decision types, so
-        # this equals filtering `selected` by the dropped set
-        landed = (selected if not n_dropped
-                  else self._decision.survivors)
-        self._meter_round(selected)
-        datas = [self._client_data(i) for i in landed]
-        context = self.strategy.round_context(datas, self.rng)
-        payloads, weights, losses = [], [], []
-        for j, (cid, data) in enumerate(zip(landed, datas, strict=True)):
-            payload, loss = self.strategy.client_step(
-                data, self.rng, None if context is None else context[j])
-            # the allocation policy may hand this client its own wire
-            # format (adaptive_codec); default is the run codec
-            codec = self.codec
-            if self._decision is not None:
-                codec = self._decision.codec_for(cid) or codec
-            if not codec.identity:
-                self._qkey, sub = jax.random.split(self._qkey)
-                if self.tracer.enabled:
-                    # wall-clock encode cost + achieved ratio live in the
-                    # metrics registry only — never on the sim timeline,
-                    # so traced replays stay deterministic
-                    t0 = time.perf_counter()  # repro: allow[RPL001]
-                    payload, res = self.strategy.compress_payload(
-                        payload, sub, self._ef_residual.get(cid),
-                        codec=codec)
-                    payload = jax.block_until_ready(payload)
-                    m = self.tracer.metrics
-                    m.histogram("codec_encode_s").observe(
-                        time.perf_counter() - t0,  # repro: allow[RPL001]
-                        codec=codec.spec())
-                    n_up = sum(ph.up_floats for ph in self.plan.phases)
-                    m.gauge("codec_ratio").set(
-                        codecs.achieved_ratio(codec, n_up),
-                        codec=codec.spec())
-                    if res is not None:
-                        m.gauge("ef_residual_norm").set(_tree_norm(res),
-                                                        client=int(cid))
-                else:
-                    payload, res = self.strategy.compress_payload(
-                        payload, sub, self._ef_residual.get(cid),
-                        codec=codec)
-                if res is not None:
-                    self._ef_residual[cid] = res
-            payloads.append(payload)
-            weights.append(len(data[0]))
-            losses.append(loss)
-        info = {"cohort": len(landed)}
-        if n_dropped:
-            info["dropped"] = n_dropped
-        if losses:
-            info["loss"] = float(np.mean(losses))
-        if self.edge is not None and self.edge.async_agg is not None:
-            # buffered async: dispatch this cohort, aggregate whatever
-            # buffer of (possibly stale) results arrives first
-            self.edge.dispatch_async(self._edge_est, weights, payloads,
-                                     self.plan.downlink_bytes())
-            entries, w_st = self.edge.pop_async_buffer()
-            if entries:
-                agg = self.strategy.aggregate(
-                    [e.payload for e in entries],
-                    jnp.asarray(w_st, jnp.float32))
-                self.strategy.server_step(agg)
-            rec = self.edge.history[-1]
-            info.update(wall_s=rec["wall_s"], sim_time_s=rec["clock_s"],
-                        energy_j=rec["energy_j"], aggregated=len(entries))
-            return info
-        if payloads:
+        tr = self.tracer
+        rid = self.ledger.rounds        # 0-based: end_round not called yet
+        with tr.wall_span("fed.round", round_id=rid):
+            with tr.wall_span("fed.sample"):
+                selected = self.sample_clients()
+                n_dropped = (0 if self._decision is None
+                             else self._decision.n_dropped)
+                # survivors preserves selection order on both decision
+                # types, so this equals filtering `selected` by the
+                # dropped set
+                landed = (selected if not n_dropped
+                          else self._decision.survivors)
+                self._meter_round(selected)
+            with tr.wall_span("fed.gather"):
+                datas = [self._client_data(i) for i in landed]
+            context = self.strategy.round_context(datas, self.rng)
+            payloads, weights, losses = [], [], []
+            for j, (cid, data) in enumerate(zip(landed, datas, strict=True)):
+                with tr.wall_span("fed.client", round_id=rid,
+                                  client=int(cid)):
+                    payload, loss = self.strategy.client_step(
+                        data, self.rng, None if context is None else context[j])
+                    payload = self._compress(cid, payload)
+                payloads.append(payload)
+                weights.append(len(data[0]))
+                losses.append(loss)
+            info = {"cohort": len(landed)}
+            if n_dropped:
+                info["dropped"] = n_dropped
+            if losses:
+                info["loss"] = float(np.mean(losses))
+            if self.edge is not None and self.edge.async_agg is not None:
+                # buffered async: dispatch this cohort, aggregate whatever
+                # buffer of (possibly stale) results arrives first
+                self.edge.dispatch_async(self._edge_est, weights, payloads,
+                                         self.plan.downlink_bytes())
+                entries, w_st = self.edge.pop_async_buffer()
+                if entries:
+                    self._server_update([e.payload for e in entries], w_st)
+                rec = self.edge.history[-1]
+                info.update(wall_s=rec["wall_s"], sim_time_s=rec["clock_s"],
+                            energy_j=rec["energy_j"], aggregated=len(entries))
+                return info
+            if payloads:
+                self._server_update(payloads, weights)
+            return self._edge_sync_finish(info)
+
+    def _compress(self, cid, payload):
+        """Round-trip one client's payload through its codec (the
+        allocation policy may hand the client its own wire format,
+        adaptive_codec's; the default is the run codec), with the
+        client's error-feedback residual, inside ``fed.compress``.  With a
+        tracer attached the span ends when the payload is ready, so its
+        duration is the encode time of ``codec_encode_s`` with or without
+        a profiler; the ratio and residual gauges live in the metrics
+        registry only — never on the sim timeline, so traced replays stay
+        deterministic."""
+        codec = self.codec
+        if self._decision is not None:
+            codec = self._decision.codec_for(cid) or codec
+        if codec.identity:
+            return payload
+        self._qkey, sub = jax.random.split(self._qkey)
+        tr = self.tracer
+        spec = codec.spec() if tr.enabled else ""
+        with tr.wall_span("fed.compress", codec=spec) as span:
+            payload, res = self.strategy.compress_payload(
+                payload, sub, self._ef_residual.get(cid), codec=codec)
+            if tr.enabled:
+                payload = jax.block_until_ready(payload)
+        if res is not None:
+            self._ef_residual[cid] = res
+        if tr.enabled:
+            m = tr.metrics
+            m.histogram("codec_encode_s").observe(span.dur, codec=spec)
+            n_up = sum(ph.up_floats for ph in self.plan.phases)
+            m.gauge("codec_ratio").set(codecs.achieved_ratio(codec, n_up),
+                                       codec=spec)
+            if res is not None:
+                m.gauge("ef_residual_norm").set(
+                    self.strategy._wait(_tree_norm(res)), client=int(cid))
+        return payload
+
+    def _server_update(self, payloads: list, weights) -> None:
+        tr = self.tracer
+        with tr.wall_span("fed.aggregate"):
             agg = self.strategy.aggregate(
                 payloads, jnp.asarray(weights, jnp.float32))
+        with tr.wall_span("fed.server_step"):
             self.strategy.server_step(agg)
-        return self._edge_sync_finish(info)
 
     # ------------------------------------------------------------------
     def evaluate(self, max_examples: int = 2000) -> float:
-        x = jnp.asarray(self.test.x[:max_examples])
-        y = jnp.asarray(self.test.y[:max_examples])
-        return self.strategy.evaluate(x, y)
+        with self.tracer.wall_span("fed.evaluate"):
+            x, y = self.strategy._put((self.test.x[:max_examples],
+                                       self.test.y[:max_examples]))
+            return self.strategy.evaluate(x, y)
 
     def run(self, rounds: Optional[int] = None, eval_every: int = 5,
             target_accuracy: Optional[float] = None, verbose: bool = False):

@@ -147,8 +147,6 @@ def with_edge(round_step: Callable, edge: EdgeRuntime, n_params: int,
     exists to forbid."""
     if tracer is not None:
         edge.tracer = tracer
-        if edge.async_agg is not None:
-            edge.async_agg.tracer = tracer
     step_codec = getattr(round_step, "codec", codecs.NONE)
     codec = step_codec if compress is None else codecs.make(compress)
     if codec.spec() != step_codec.spec():

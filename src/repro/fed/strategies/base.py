@@ -19,6 +19,13 @@ never branches on the algorithm name.  A strategy declares:
     staleness-weighted aggregation).
   * ``server_step(aggregate)`` — apply the aggregate to the server model.
 
+A strategy moves data and waits on the device through three helpers,
+``_put`` (host→device copy of a batch), ``_dispatch`` (a jitted call)
+and ``_wait`` (a host read of a device value), which put each of those
+host steps on the run's tracer as ``fed.put`` / ``fed.dispatch`` /
+``fed.wait`` spans and count ``h2d_bytes_total`` and
+``device_syncs_total`` (see :mod:`repro.obs.trace`).
+
 Multi-phase algorithms (FedDANE's gradient round before the inner solves)
 implement ``round_context``, which sees the whole cohort once and hands
 each client its per-client context; the extra phase's bytes live in the
@@ -39,9 +46,11 @@ from typing import Any, Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import aggregation
 from repro.fed import codecs, comm
+from repro.obs import trace as obs
 
 
 # ---------------------------------------------------------------------------
@@ -118,6 +127,8 @@ class FedStrategy(abc.ABC):
     edge runtime, and the client loop."""
 
     name: str = ""  # filled in by ``register``
+    # the run's tracer (FederatedRun.tracer hands it over)
+    tracer = obs.NULL_TRACER
 
     def __init__(self, model_cfg: Any, fed_cfg: Any, n_classes: int):
         self.mcfg = model_cfg
@@ -227,12 +238,43 @@ class FedStrategy(abc.ABC):
         must not be quantized) override this."""
         return (codec or self.codec).roundtrip(payload, key, residual)
 
+    # -- host steps on the tracer ----------------------------------------
+    def _put(self, tree: Any) -> Any:
+        """Copy a pytree of host arrays to the device, in ``fed.put``; its
+        ``bytes`` (those of the device arrays made) also go to
+        ``h2d_bytes_total``, labelled by the step that pays for them."""
+        tr = self.tracer
+        if not tr.enabled:
+            return jax.tree.map(jnp.asarray, tree)
+        # host arrays are copied at the dtype JAX gives them (int64
+        # labels arrive as int32 unless x64 is on); device arrays stay
+        nbytes = sum(a.size * jax.dtypes.canonicalize_dtype(a.dtype).itemsize
+                     for a in jax.tree.leaves(tree)
+                     if isinstance(a, np.ndarray))
+        tr.count("h2d_bytes_total", nbytes)
+        with tr.wall_span("fed.put", bytes=nbytes):
+            return jax.tree.map(jnp.asarray, tree)
+
+    def _dispatch(self, fn: Callable, *args, **kwargs) -> Any:
+        """Call ``fn`` (a jitted step), in ``fed.dispatch``: the span ends
+        when the call returns, before its work ends on the device."""
+        with self.tracer.wall_span("fed.dispatch"):
+            return fn(*args, **kwargs)
+
+    def _wait(self, value: Any) -> float:
+        """Read a device scalar on the host, in ``fed.wait``: the host
+        blocks until the device has computed it (``device_syncs_total``)."""
+        tr = self.tracer
+        tr.count("device_syncs_total")
+        with tr.wall_span("fed.wait"):
+            return float(value)
+
     # -- evaluation ------------------------------------------------------
     def evaluate(self, x: Any, y: Any) -> float:
         """Test accuracy of the current server model.  Default: the
         jitted ``self._eval`` over ``self.params`` (built in ``_build``);
         strategies with other model state override."""
-        return float(self._eval(self.params, x, y))
+        return self._wait(self._dispatch(self._eval, self.params, x, y))
 
 
 # ---------------------------------------------------------------------------

@@ -52,11 +52,11 @@ class LocalSolveStrategy(FedStrategy):
 
     def client_step(self, data, rng, context=None):
         xs, ys = data
-        batches = fed_client.stack_batches(
-            xs, ys, self.fcfg.batch_size, self.fcfg.local_epochs, rng)
-        p, loss = self._local_solve(self.params, batches)
+        batches = self._put(fed_client.stack_batches(
+            xs, ys, self.fcfg.batch_size, self.fcfg.local_epochs, rng))
+        p, loss = self._dispatch(self._local_solve, self.params, batches)
         delta = jax.tree.map(lambda a, b: a - b, p, self.params)
-        return delta, float(loss)
+        return delta, self._wait(loss)
 
     def server_step(self, aggregate) -> None:
         self.params = jax.tree.map(lambda p, dl: p + dl,

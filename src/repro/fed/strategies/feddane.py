@@ -58,8 +58,8 @@ class FedDaneStrategy(FedStrategy):
             return []
         local_grads, sent_grads, weights = [], [], []
         for xs, ys in datas:
-            batch = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
-            g, _, _ = self._grad_fim(self.params, batch)
+            batch = self._put({"x": xs, "y": ys})
+            g, _, _ = self._dispatch(self._grad_fim, self.params, batch)
             local_grads.append(g)  # the client keeps its exact gradient
             if not self.codec.identity:
                 self._ckey, sub = jax.random.split(self._ckey)
@@ -74,11 +74,12 @@ class FedDaneStrategy(FedStrategy):
     def client_step(self, data, rng, context=None):
         xs, ys = data
         global_grad, g0 = context
-        batches = fed_client.stack_batches(
-            xs, ys, self.fcfg.batch_size, self.fcfg.local_epochs, rng)
-        p, loss = self._dane(self.params, batches, global_grad, g0,
-                             lr=float(self.fcfg.learning_rate), mu=0.1)
-        return p, float(loss)
+        batches = self._put(fed_client.stack_batches(
+            xs, ys, self.fcfg.batch_size, self.fcfg.local_epochs, rng))
+        p, loss = self._dispatch(self._dane, self.params, batches,
+                                 global_grad, g0,
+                                 lr=float(self.fcfg.learning_rate), mu=0.1)
+        return p, self._wait(loss)
 
     def server_step(self, aggregate) -> None:
         self.params = aggregate

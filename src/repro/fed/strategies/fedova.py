@@ -96,29 +96,29 @@ class FedOvaStrategy(FedStrategy):
             c = int(c)
             mask[c] = 1.0
             yb = (ys == c).astype(np.int64)
-            batches = fed_client.stack_batches(
-                xs, yb, self.fcfg.batch_size, self.fcfg.local_epochs, rng)
+            batches = self._put(fed_client.stack_batches(
+                xs, yb, self.fcfg.batch_size, self.fcfg.local_epochs, rng))
             comp_c = jax.tree.map(lambda leaf, cc=c: leaf[cc],
                                   self.model.components)
             comp_new, loss = self._train_component(c, comp_c, batches)
             client_comp = jax.tree.map(
                 lambda full, new, cc=c: full.at[cc].set(new),
                 client_comp, comp_new)
-            losses.append(float(loss))
+            losses.append(self._wait(loss))
         return (client_comp, mask), float(np.mean(losses)) if losses else float("nan")
 
     def _train_component(self, c, comp_c, batches):
         if self.server_opt == "fim_lbfgs":
             big = {"x": batches["x"].reshape((-1,) + batches["x"].shape[2:]),
                    "y": batches["y"].reshape(-1)}
-            g, f, loss = self._grad_fim(comp_c, big)
+            g, f, loss = self._dispatch(self._grad_fim, comp_c, big)
             ost = jax.tree.map(lambda s: s[c], self.opt_state)
             comp_new, ost, _ = fim_lbfgs.update(ost, comp_c, g, f, self.ocfg)
             self.opt_state = jax.tree.map(
                 lambda s, o: s.at[c].set(o), self.opt_state, ost)
             return comp_new, loss
-        return self._local_sgd(comp_c, batches,
-                               lr=float(self.fcfg.learning_rate))
+        return self._dispatch(self._local_sgd, comp_c, batches,
+                              lr=float(self.fcfg.learning_rate))
 
     def compress_payload(self, payload, key, residual=None, codec=None):
         # codec the component stack only: the class-presence mask is
@@ -138,7 +138,8 @@ class FedOvaStrategy(FedStrategy):
         self.model = fedova.aggregate(self.model, stacked, masks)
 
     def evaluate(self, x, y) -> float:
-        return float(fedova.accuracy(self._apply, self.model, x, y))
+        return self._wait(self._dispatch(fedova.accuracy, self._apply,
+                                         self.model, x, y))
 
 
 register("fedova", FedOvaStrategy)

@@ -50,9 +50,9 @@ class FimLbfgsStrategy(FedStrategy):
         # Full local gradient/Fisher (the ERM F_k over D_k, as in
         # DANE/GIANT); stochastic batches are exercised by the LLM-scale
         # path where full data is impossible.
-        batch = {"x": jnp.asarray(xs), "y": jnp.asarray(ys)}
-        g, f, loss = self._grad_fim(self.params, batch)
-        return (g, f), float(loss)
+        batch = self._put({"x": xs, "y": ys})
+        g, f, loss = self._dispatch(self._grad_fim, self.params, batch)
+        return (g, f), self._wait(loss)
 
     def compress_payload(self, payload, key, residual=None, codec=None):
         out, residual = (codec or self.codec).roundtrip(payload, key, residual)

@@ -16,6 +16,12 @@ Attach a :class:`Tracer` to a run::
     obs.write_jsonl(tracer, "trace.jsonl")
     tracer.audit.verify(run.ledger)              # plan == ledger, audited
 
+The round's host steps (``fed.round``, ``fed.client``, ``fed.put`` …)
+are ``Tracer.wall_span`` blocks, each a ``jax.profiler.TraceAnnotation``:
+under ``jax.profiler.trace`` they sit beside the device ops.  A tracer
+can be attached for some rounds only (``run.tracer = tracer``, then
+``run.tracer = None``).
+
 The default is :data:`NULL_TRACER` — a shared no-op — so the
 instrumented hot path costs nothing when tracing is off.
 """
