@@ -36,13 +36,16 @@ def init(params, dtype=jnp.float32) -> FimState:
     )
 
 
-def _leaf_diag(g2, kernels: str):
-    """(B, D) per-example gradients -> (D,) mean of squares, via the
-    fused Pallas op (repro.kernels.ops).  With old=0 and ema=0 the fused
-    Γ update reduces to exactly mean_b g² — bit-identical to the inline
-    jnp expression on the oracle path."""
-    zeros = jnp.zeros((g2.shape[1],), jnp.float32)
-    return kernel_ops.fim_diag_update(g2, zeros, 0.0, mode=kernels)
+def _leaf_diag(g, kernels: str):
+    """(B, *leaf_shape) per-example gradients -> leaf_shape mean of
+    squares, via the fused Pallas op (repro.kernels.ops).  The gradients
+    go in the shape ``vmap(grad)`` gives them: the kernel views a leaf as
+    (B, R, C) in place, where a (B, D) flatten would cost a transposing
+    copy and pads of B on every large leaf.  With old=0 and ema=0 the
+    fused Γ update reduces to exactly mean_b g² — bit-identical to the
+    inline jnp expression on the oracle path."""
+    zeros = jnp.zeros(g.shape[1:], jnp.float32)
+    return kernel_ops.fim_diag_update(g, zeros, 0.0, mode=kernels)
 
 
 def per_example_diag(per_example_loss: Callable, params, xs, ys,
@@ -51,20 +54,17 @@ def per_example_diag(per_example_loss: Callable, params, xs, ys,
     per-example gradients.  ``per_example_loss(params, x, y) -> scalar``.
 
     ``kernels`` routes the square+mean through the fused Pallas op
-    (repro.kernels.ops.fim_diag_update); "off"/non-TPU "auto" resolve to
+    (repro.kernels.ops.fim_diag_update), one call per leaf on the leaf's
+    (B, *shape) gradients as they are; "off"/non-TPU "auto" resolve to
     the bit-identical jnp oracle."""
     grads = jax.vmap(lambda x, y: jax.grad(per_example_loss)(params, x, y))(xs, ys)
-    return jax.tree.map(
-        lambda g: _leaf_diag(g.reshape(g.shape[0], -1),
-                             kernels).reshape(g.shape[1:]), grads)
+    return jax.tree.map(lambda g: _leaf_diag(g, kernels), grads)
 
 
 def microbatch_diag(grad, kernels: str = "off"):
     """Squared (micro)batch gradient — one term of the accumulation mean
-    (a B=1 instance of the same fused Γ op)."""
-    return jax.tree.map(
-        lambda g: _leaf_diag(g.reshape(1, -1), kernels).reshape(g.shape),
-        grad)
+    (a B=1 instance of the same fused Γ op, on a (1, *shape) view)."""
+    return jax.tree.map(lambda g: _leaf_diag(g[None], kernels), grad)
 
 
 def update(state: FimState, new_diag, ema: float) -> FimState:

@@ -50,7 +50,9 @@ def resolve(mode: str, force_kernel: bool = False) -> str:
 
 def fim_diag_update(grads, old_diag, ema, force_kernel: bool = False,
                     mode: str = "auto"):
-    """Fused Γ update: ema*old + (1-ema)*mean_b g².  grads: (B, D)."""
+    """Fused Γ update: ema*old + (1-ema)*mean_b g².  grads: (B, *shape),
+    one leaf's per-example gradients in the leaf's own shape (a (B, D)
+    matrix is the shape (D,)); old_diag: shape."""
     path = resolve(mode, force_kernel)
     if path == "oracle":
         return ref.fim_diag_ref(grads, old_diag, ema)

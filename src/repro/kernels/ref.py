@@ -19,8 +19,9 @@ TOPK_SHIFT = 22
 
 
 def fim_diag_ref(grads, old_diag, ema: float):
-    """grads: (B, D) per-example (or per-microbatch) gradients;
-    old_diag: (D,) f32 EMA state.  Returns ema*old + (1-ema)*mean(g²)."""
+    """grads: (B, *shape) per-example (or per-microbatch) gradients;
+    old_diag: shape, f32 EMA state.  Returns ema*old + (1-ema)*mean(g²)
+    over the batch axis."""
     meansq = jnp.mean(jnp.square(grads.astype(jnp.float32)), axis=0)
     return ema * old_diag.astype(jnp.float32) + (1.0 - ema) * meansq
 

@@ -8,17 +8,27 @@ import pytest
 from repro.kernels import ops, ref
 
 
-# (300, 3000) / (300, 5000): true multi-block tails on BOTH grid axes
-# (B % B_BLK and D % D_BLK nonzero) — the tail-tile leak regression
+# (B, D) matrices, D an int: (300, 3000) / (300, 5000) have true
+# multi-block tails on both grid axes — the tail-tile leak regression.
+# Leaf shapes, D a tuple: the kernel takes (B, *D) in place, viewed as
+# (B, R, C) — conv leaves, a last dim past one lane tile, a bias, the
+# B=1 microbatch row, and batch tails no block divides ((300, 3x3x16x24)
+# and (13, 264x256) end on a part-filled batch block; (13, 264x256) also
+# ends on a part-filled row block).
 @pytest.mark.parametrize("B,D", [(8, 256), (64, 1000), (256, 4096), (5, 131),
-                                 (300, 3000), (300, 5000), (257, 2049)])
+                                 (300, 3000), (300, 5000), (257, 2049),
+                                 (5, (3, 3, 3, 8)), (300, (3, 3, 16, 24)),
+                                 (7, (6, 130)), (9, (64,)), (1, (33, 257)),
+                                 (13, (264, 256))])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_fim_diag_kernel(B, D, dtype):
-    key = jax.random.PRNGKey(B * D)
-    g = jax.random.normal(key, (B, D), dtype)
-    old = jax.random.uniform(jax.random.PRNGKey(1), (D,), jnp.float32)
+    leaf = D if isinstance(D, tuple) else (D,)
+    key = jax.random.PRNGKey(B * int(np.prod(leaf)))
+    g = jax.random.normal(key, (B, *leaf), dtype)
+    old = jax.random.uniform(jax.random.PRNGKey(1), leaf, jnp.float32)
     out_k = ops.fim_diag_update(g, old, 0.9, force_kernel=True)
     out_r = ref.fim_diag_ref(g, old, 0.9)
+    assert out_k.shape == leaf
     tol = 1e-5 if dtype == jnp.float32 else 5e-2
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_r),
                                rtol=tol, atol=tol)
